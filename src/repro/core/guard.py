@@ -47,6 +47,10 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
+# JAX 0.9 exports no public "am I tracing?" query.  trace_state_clean is
+# the function the removed ``jax.core.trace_state_clean`` alias named (it
+# returns ``trace_ctx.is_top_level()``), so its meaning here is unchanged.
+from jax._src.core import trace_state_clean
 
 __all__ = [
     "HotPathViolation",
@@ -263,7 +267,7 @@ class _PatchSet:
         def _h2d_check(x):
             # constants materialized during tracing are baked into the
             # compiled program, not per-dispatch transfers — skip them
-            if _is_host_array(x) and jax.core.trace_state_clean():
+            if _is_host_array(x) and trace_state_clean():
                 _record_violation("host->device", x)
 
         @_outermost

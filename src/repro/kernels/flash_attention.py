@@ -11,10 +11,12 @@ materialized head repetition (saves Hq/Hkv × KV bandwidth).
 Causal masking, sliding windows and the chunked-prefill ``q_offset`` are all
 position masks computed from grid coordinates (no mask tensors in HBM).
 Sequence-packed rows add one more mask term: per-token ``segment_ids``
-(B, Skv) int32 over the key axis stream in as (1, blk) tiles alongside q
-and k, and the score mask requires ``seg[q] == seg[kv]`` — packed segments
-never attend across their boundary, at the cost of two int32 tiles (no
-(S, S) mask in HBM).  The q chunk's labels are the kv labels sliced at
+(B, Skv) int32 over the key axis stream in alongside q and k (q labels
+as a lane-broadcast (blk_q, 128) tile, kv labels as a sublane-broadcast
+(8, blk_k) tile), and the score mask requires ``seg[q] == seg[kv]`` —
+packed segments never attend across their boundary, at the cost of two
+int32 tiles (no (S, S) mask in HBM).  q/k/v/o are passed head-major
+(B, H, S, D) so every block is a (rows, D) tile of one head.  The q chunk's labels are the kv labels sliced at
 ``q_offset`` (chunked prefill packs too), and kv labels equal to
 ``SHARED_SEGMENT_ID`` (-2; a per-row modality prefix) are attendable by
 every query.
@@ -28,10 +30,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 from repro.kernels.ref import SHARED_SEGMENT_ID
 
 _NEG_INF = -1e30
+# Mosaic's native (sublane, lane) tile
+_SUBLANES, _LANES = 8, 128
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, *refs,
@@ -56,9 +59,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, *refs,
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)          # (blk_q, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)          # (blk_k, D)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    q = q_ref[...].astype(jnp.float32)                  # (blk_q, D)
+    k = k_ref[...].astype(jnp.float32)                  # (blk_k, D)
+    v = v_ref[...].astype(jnp.float32)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -72,29 +75,28 @@ def _flash_kernel(q_ref, k_ref, v_ref, *refs,
     if window > 0:
         mask &= kpos > qpos - window
     if has_seg:
-        qseg = qseg_ref[0, :]                          # (blk_q,)
-        kseg = kseg_ref[0, :]                          # (blk_k,)
-        mask &= ((qseg[:, None] == kseg[None, :])
-                 | (kseg[None, :] == SHARED_SEGMENT_ID))
+        qseg = qseg_ref[:, :1]                          # (blk_q, 1)
+        kseg = kseg_ref[:1, :]                          # (1, blk_k)
+        mask &= (qseg == kseg) | (kseg == SHARED_SEGMENT_ID)
     s = jnp.where(mask, s, _NEG_INF)
     if has_bias:
         # same order as attention_ref: bias lands on the already-masked
         # logits, so a masked score stays ~-1e30 for any finite bias
-        s = s + bias_ref[0, 0, :, :].astype(jnp.float32)
+        s = s + bias_ref[...].astype(jnp.float32)
 
-    m_prev = m_ref[...]                                 # (blk_q,)
-    m_cur = jnp.maximum(m_prev, s.max(axis=1))
+    m_prev = m_ref[...]                                 # (blk_q, 1)
+    m_cur = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
     alpha = jnp.exp(m_prev - m_cur)
-    p = jnp.exp(s - m_cur[:, None])
-    l_ref[...] = l_ref[...] * alpha + p.sum(axis=1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+    p = jnp.exp(s - m_cur)
+    l_ref[...] = l_ref[...] * alpha + p.sum(axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
     m_ref[...] = m_cur
 
     @pl.when(ik == nk - 1)
     def _flush():
-        denom = jnp.maximum(l_ref[...], 1e-30)[:, None]
-        o_ref[0, :, 0, :] = (acc_ref[...] / denom).astype(o_ref.dtype)
+        denom = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[...] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -132,32 +134,39 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
     blk_k = min(blk_k, max(Skv, 1))
     pad_q = (-Sq) % blk_q
     pad_k = (-Skv) % blk_k
-    if pad_q:
-        q = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0), (0, 0)))
-    if pad_k:
-        k = jnp.pad(k, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
-    nq = q.shape[1] // blk_q
-    nk = k.shape[1] // blk_k
+    # head-major operands: every block is a (rows, D) tile of one head,
+    # the layout Mosaic tiles natively on its last two dims
+    qh = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
+    kh = jnp.pad(k, ((0, 0), (0, pad_k), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
+    vh = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
+    nq = qh.shape[2] // blk_q
+    nk = kh.shape[2] // blk_k
 
     in_specs = [
-        pl.BlockSpec((1, blk_q, 1, D), lambda b, h, i, j: (b, i, h, 0)),
-        pl.BlockSpec((1, blk_k, 1, D),
-                     lambda b, h, i, j: (b, j, h // group, 0)),
-        pl.BlockSpec((1, blk_k, 1, D),
-                     lambda b, h, i, j: (b, j, h // group, 0)),
+        pl.BlockSpec((None, None, blk_q, D), lambda b, h, i, j: (b, h, i, 0)),
+        pl.BlockSpec((None, None, blk_k, D),
+                     lambda b, h, i, j: (b, h // group, j, 0)),
+        pl.BlockSpec((None, None, blk_k, D),
+                     lambda b, h, i, j: (b, h // group, j, 0)),
     ]
-    inputs = [q, k, v]
+    inputs = [qh, kh, vh]
     if has_seg:
         # -1 on the kv pad tail can never equal a real q segment id of a
         # surviving (un-sliced) row; the kpos < skv term masks it anyway.
+        # q labels run down the sublanes and kv labels along the lanes,
+        # each broadcast to a full (8, 128) tile so the kernel reads a
+        # (blk_q, 1) column and a (1, blk_k) row without a relayout.
         seg = segment_ids.astype(jnp.int32)
         qseg = jnp.pad(seg[:, q_offset: q_offset + Sq],
                        ((0, 0), (0, pad_q)), constant_values=-1)
         kseg = jnp.pad(seg, ((0, 0), (0, pad_k)), constant_values=-1)
+        qseg = jnp.broadcast_to(qseg[:, :, None], qseg.shape + (_LANES,))
+        kseg = jnp.broadcast_to(kseg[:, None, :],
+                                (B, _SUBLANES, kseg.shape[1]))
         in_specs += [
-            pl.BlockSpec((1, blk_q), lambda b, h, i, j: (b, i)),
-            pl.BlockSpec((1, blk_k), lambda b, h, i, j: (b, j)),
+            pl.BlockSpec((None, blk_q, _LANES), lambda b, h, i, j: (b, i, 0)),
+            pl.BlockSpec((None, _SUBLANES, blk_k),
+                         lambda b, h, i, j: (b, 0, j)),
         ]
         inputs += [qseg, kseg]
     if has_bias:
@@ -168,7 +177,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
         bias_full = jnp.pad(bias_full,
                             ((0, 0), (0, 0), (0, pad_q), (0, pad_k)))
         in_specs += [
-            pl.BlockSpec((1, 1, blk_q, blk_k),
+            pl.BlockSpec((None, None, blk_q, blk_k),
                          lambda b, h, i, j: (b, h, i, j)),
         ]
         inputs += [bias_full]
@@ -180,20 +189,18 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
             has_seg=has_seg, has_bias=has_bias),
         grid=(B, Hq, nq, nk),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, blk_q, 1, D),
-                               lambda b, h, i, j: (b, i, h, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_specs=pl.BlockSpec((None, None, blk_q, D),
+                               lambda b, h, i, j: (b, h, i, 0)),
+        out_shape=jax.ShapeDtypeStruct(qh.shape, q.dtype),
         scratch_shapes=[
             pltpu.VMEM((blk_q, D), jnp.float32),
-            pltpu.VMEM((blk_q,), jnp.float32),
-            pltpu.VMEM((blk_q,), jnp.float32),
+            pltpu.VMEM((blk_q, 1), jnp.float32),
+            pltpu.VMEM((blk_q, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
         interpret=interpret,
     )(*inputs)
-    if pad_q:
-        out = out[:, :Sq]
-    return out
+    return out.transpose(0, 2, 1, 3)[:, :Sq]

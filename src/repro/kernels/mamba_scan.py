@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 from repro.kernels.ref import segment_reset_mask
 
 
@@ -101,7 +100,7 @@ def mamba_scan_pallas(u, dt, B_, C_, A, D, h0, segment_ids=None, *,
             jax.ShapeDtypeStruct((B, d_in, N), h0.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((blk_d, N), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,

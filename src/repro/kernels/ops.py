@@ -1,30 +1,69 @@
 """Public kernel entry points.
 
 Each op dispatches between the Pallas TPU kernel and the pure-jnp reference
-depending on backend/flags.  On this CPU container the jnp path (or the
-Pallas interpreter in tests) executes; on TPU the pallas_call path compiles.
+depending on the backend.  On a TPU every op runs its Pallas kernel; on
+the CPU the jnp reference runs, or the Pallas interpreter in tests
+(``REPRO_PALLAS_INTERPRET=1``).  ``REPRO_FORCE_REF=1`` forces the
+references on the CPU.  Both switches raise on a TPU backend, so a chip
+run can never quietly measure the interpreter or the reference.
 
-Set ``REPRO_FORCE_REF=1`` to force reference implementations everywhere.
+``pallas_call`` has no transpose rule, so reverse-mode autodiff cannot
+pass through a kernel: the ops a training forward uses run the kernel
+forward and take their gradient from the reference's VJP
+(:func:`_with_ref_grad`).
 """
 from __future__ import annotations
 
-import functools
 import os
 
 import jax
 import jax.numpy as jnp
 
 
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _cpu_switch(name: str) -> bool:
+    on = os.environ.get(name, "0") == "1"
+    if on and _on_tpu():
+        raise RuntimeError(
+            f"{name}=1 is a CPU-only switch; on a TPU backend every op "
+            "runs its compiled Pallas kernel")
+    return on
+
+
 def _interpret() -> bool:
     """REPRO_PALLAS_INTERPRET=1 routes ops through the Pallas interpreter on
     CPU — used by tests to exercise the real kernel bodies end-to-end."""
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "0") == "1"
+    return _cpu_switch("REPRO_PALLAS_INTERPRET")
 
 
 def _use_pallas() -> bool:
-    if os.environ.get("REPRO_FORCE_REF", "0") == "1":
+    if _cpu_switch("REPRO_FORCE_REF"):
         return False
-    return jax.default_backend() == "tpu" or _interpret()
+    return _on_tpu() or _interpret()
+
+
+def _with_ref_grad(kernel, reference):
+    """``kernel``'s forward with ``reference``'s gradient.  The backward
+    recomputes the reference from the saved inputs and applies its VJP;
+    integer inputs (segment ids) get the usual float0 cotangents.
+
+    Callers pass lambdas that *call* both functions: ``tools/analyze``
+    R4 pairs a dispatch's kernel and reference by their call sites."""
+    @jax.custom_vjp
+    def f(*args):
+        return kernel(*args)
+
+    def fwd(*args):
+        return kernel(*args), args
+
+    def bwd(args, g):
+        return jax.vjp(reference, *args)[1](g)
+
+    f.defvjp(fwd, bwd)
+    return f
 
 
 # ---------------------------------------------------------------------------
@@ -32,12 +71,15 @@ def _use_pallas() -> bool:
 # ---------------------------------------------------------------------------
 
 def rmsnorm(x, scale, eps: float = 1e-6):
+    from repro.kernels.ref import rmsnorm_ref
+
     if _use_pallas():
         from repro.kernels.rmsnorm import rmsnorm_pallas
 
-        return rmsnorm_pallas(x, scale, eps=eps, interpret=_interpret())
-    from repro.kernels.ref import rmsnorm_ref
-
+        return _with_ref_grad(
+            lambda x, scale: rmsnorm_pallas(x, scale, eps=eps,
+                                            interpret=_interpret()),
+            lambda x, scale: rmsnorm_ref(x, scale, eps=eps))(x, scale)
     return rmsnorm_ref(x, scale, eps=eps)
 
 
@@ -61,20 +103,27 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     (B, Hq, Sq, Skv), added to the masked logits (ALiBi, relative
     position, soft prompt masks); supported by both backends.
     """
+    from repro.kernels.ref import attention_ref
+
+    static = dict(causal=causal, window=window, scale=scale,
+                  q_offset=q_offset)
     # the Pallas kernel tiles one head dim for q/k/v; MLA prefill attends
     # with qk_head_dim != v_head_dim, which only the reference supports.
     if _use_pallas() and q.shape[-1] == v.shape[-1]:
         from repro.kernels.flash_attention import flash_attention_pallas
 
-        return flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                      scale=scale, q_offset=q_offset,
-                                      segment_ids=segment_ids, bias=bias,
-                                      interpret=_interpret())
-    from repro.kernels.ref import attention_ref
+        def kernel(q, k, v, segment_ids, bias):
+            return flash_attention_pallas(
+                q, k, v, segment_ids=segment_ids, bias=bias,
+                interpret=_interpret(), **static)
 
-    return attention_ref(q, k, v, causal=causal, window=window, scale=scale,
-                         q_offset=q_offset, segment_ids=segment_ids,
-                         bias=bias)
+        def reference(q, k, v, segment_ids, bias):
+            return attention_ref(q, k, v, segment_ids=segment_ids,
+                                 bias=bias, **static)
+
+        return _with_ref_grad(kernel, reference)(q, k, v, segment_ids, bias)
+    return attention_ref(q, k, v, segment_ids=segment_ids, bias=bias,
+                         **static)
 
 
 # ---------------------------------------------------------------------------
@@ -213,13 +262,15 @@ def mamba_scan(u, dt, B_, C_, A, D, h0, segment_ids=None):
     ``segment_ids``: optional (B, T) packed-row labels — the carried
     state is zeroed at every segment start, so packed segments scan
     exactly as they would in their own rows."""
+    from repro.kernels.ref import mamba_scan_ref
+
     if _use_pallas():
         from repro.kernels.mamba_scan import mamba_scan_pallas
 
-        return mamba_scan_pallas(u, dt, B_, C_, A, D, h0, segment_ids,
-                                 interpret=_interpret())
-    from repro.kernels.ref import mamba_scan_ref
-
+        return _with_ref_grad(
+            lambda *a: mamba_scan_pallas(*a, interpret=_interpret()),
+            lambda *a: mamba_scan_ref(*a))(u, dt, B_, C_, A, D, h0,
+                                           segment_ids)
     return mamba_scan_ref(u, dt, B_, C_, A, D, h0,
                           segment_ids=segment_ids)
 
@@ -236,11 +287,12 @@ def wkv6(r, k, v, w, u, state, segment_ids=None):
 
     ``segment_ids``: optional (B, T) packed-row labels — the carried
     state is zeroed at every segment start (no cross-segment wkv leak)."""
+    from repro.kernels.ref import wkv6_ref
+
     if _use_pallas():
         from repro.kernels.wkv6 import wkv6_pallas
 
-        return wkv6_pallas(r, k, v, w, u, state, segment_ids,
-                           interpret=_interpret())
-    from repro.kernels.ref import wkv6_ref
-
+        return _with_ref_grad(
+            lambda *a: wkv6_pallas(*a, interpret=_interpret()),
+            lambda *a: wkv6_ref(*a))(r, k, v, w, u, state, segment_ids)
     return wkv6_ref(r, k, v, w, u, state, segment_ids=segment_ids)

@@ -46,7 +46,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import ANY_MEMORY_SPACE, CompilerParams
 
 _NEG_INF = -1e30
 
@@ -145,7 +144,7 @@ def paged_attention_pallas(q, k_pool, v_pool, block_tables, lengths, *,
                           page_size=page_size, group=group, window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -249,7 +248,7 @@ def mla_paged_attention_pallas(q_lat, q_rope, ckv_pool, kr_pool,
                           page_size=page_size),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, r), q_lat.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -375,7 +374,7 @@ def fused_paged_attention_pallas(q, kv_pool, block_tables, lengths, *,
         grid=(B,),
         in_specs=[
             pl.BlockSpec((1, Hq, D), lambda b, tbl, ln: (b, 0, 0)),
-            pl.BlockSpec(memory_space=ANY_MEMORY_SPACE),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, Hq, D), lambda b, tbl, ln: (b, 0, 0)),
         scratch_shapes=[
@@ -391,7 +390,7 @@ def fused_paged_attention_pallas(q, kv_pool, block_tables, lengths, *,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         # the manual DMA ring (buf/sem scratch) is shared state across
         # grid steps: the batch dim must not be megacore-parallelized
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
@@ -488,7 +487,7 @@ def mla_fused_paged_attention_pallas(q_lat, q_rope, kv_pool, block_tables,
         in_specs=[
             pl.BlockSpec((1, H, r), lambda b, tbl, ln: (b, 0, 0)),
             pl.BlockSpec((1, H, feat - r), lambda b, tbl, ln: (b, 0, 0)),
-            pl.BlockSpec(memory_space=ANY_MEMORY_SPACE),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, H, r), lambda b, tbl, ln: (b, 0, 0)),
         scratch_shapes=[
@@ -501,7 +500,7 @@ def mla_fused_paged_attention_pallas(q_lat, q_rope, kv_pool, block_tables,
                           page_size=page_size, rank=r, depth=depth),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, r), q_lat.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 from repro.kernels.ref import segment_reset_mask
 
 
@@ -91,7 +90,7 @@ def wkv6_pallas(r, k, v, w, u, state, segment_ids=None, *,
             jax.ShapeDtypeStruct((B, H, D, D), state.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((D, D), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,

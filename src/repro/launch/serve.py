@@ -19,6 +19,7 @@ import argparse
 import random
 import time
 from collections import Counter
+from typing import List, Tuple
 
 import jax
 
@@ -26,10 +27,12 @@ from repro.configs import get_config
 from repro.configs.base import TreeConfig
 from repro.core.engine import TreeEngine
 from repro.core.sampler import sample_sequential, sample_trees
-from repro.core.scheduler import Request, Scheduler, poisson_trace
+from repro.core.scheduler import (Request, Scheduler, ServeReport,
+                                  poisson_trace)
 from repro.data.reward import extract_boxed, verify_answer
 from repro.data.synthetic_math import MathTaskGenerator
 from repro.data.tokenizer import ByteTokenizer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import init_params
 
 SYSTEM_PROMPT = ("You are a careful math assistant. Work step by step "
@@ -37,7 +40,9 @@ SYSTEM_PROMPT = ("You are a careful math assistant. Work step by step "
 
 
 def serve_requests(args, engine: TreeEngine, tok: ByteTokenizer,
-                   rng) -> None:
+                   rng) -> Tuple[List[Request], ServeReport]:
+    """Serve ``args.requests`` seeded math prompts on a Poisson trace and
+    print the summary; returns the finished requests and the report."""
     gen = MathTaskGenerator(seed=args.seed, min_difficulty=1,
                             max_difficulty=2)
     samples = gen.batch(args.requests)
@@ -65,6 +70,7 @@ def serve_requests(args, engine: TreeEngine, tok: ByteTokenizer,
           f"({report.radix_hit_tokens}/{report.prompt_tokens} prompt "
           f"tokens from cache; {report.evicted_pages} pages evicted)")
     print(f"  peak KV pages: {engine.stats.peak_pages}")
+    return reqs, report
 
 
 def serve_trees(args, engine: TreeEngine, tok: ByteTokenizer,
@@ -125,6 +131,7 @@ def main() -> None:
                     choices=["tree", "sequential"])
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     tok = ByteTokenizer()
     cfg = get_config(args.arch)

@@ -31,6 +31,7 @@ from repro.checkpoint import latest_step, load_checkpoint, save_checkpoint
 from repro.configs import get_config
 from repro.configs.base import TrainConfig, TreeConfig
 from repro.core import faults
+from repro.launch.compile_cache import enable_compile_cache
 from repro.rl.trainer import RLTrainer, TrainerMode
 
 
@@ -61,6 +62,7 @@ def main() -> None:
     ap.add_argument("--log", default=None, help="JSONL metrics path")
     ap.add_argument("--eval-every", type=int, default=5)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     tree_cfg = TreeConfig(

@@ -420,6 +420,10 @@ def test_launch_driver_crash_and_resume(tmp_path, monkeypatch, capsys):
     uninterrupted run's."""
     from repro.launch import train as launch_train
 
+    # main() turns on the persistent compile cache, in <repo>/.jax_cache
+    # unless this is set: keep this test process's compiles out of the repo
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jc"))
+
     def run(extra, ckpt, log):
         argv = ["train", "--arch", "qwen2.5-7b-smoke", "--mode", "treepo",
                 "--steps", "4", "--bc-steps", "2", "--queries", "2",

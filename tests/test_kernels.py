@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 from numpy.testing import assert_allclose
 
-from repro.kernels import ref
+from repro.kernels import ops, ref
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.mamba_scan import mamba_scan_pallas
 from repro.kernels.paged_attention import (
@@ -712,3 +712,69 @@ def test_wkv6_state_chaining():
     assert_allclose(np.asarray(jnp.concatenate([o1, o2], 1)),
                     np.asarray(o_full), rtol=5e-4, atol=5e-4)
     assert_allclose(np.asarray(s2), np.asarray(s_full), rtol=5e-4, atol=5e-4)
+
+
+# ---------------------------------------------------------------------------
+# ops dispatch: gradients through kernels, CPU-only switches
+# ---------------------------------------------------------------------------
+
+def _grad_cases():
+    ks = jax.random.split(jax.random.PRNGKey(21), 8)
+    seg = jnp.asarray(np.repeat([[0] * 10 + [1] * 14], 2, 0), jnp.int32)
+    q = jax.random.normal(ks[0], (2, 24, 4, 32))
+    kv = [jax.random.normal(ks[i], (2, 24, 2, 32)) for i in (1, 2)]
+    B, T, d_in, N, H, D = 1, 8, 16, 4, 2, 8
+    mamba = (jax.random.normal(ks[3], (B, T, d_in)),
+             jax.nn.softplus(jax.random.normal(ks[4], (B, T, d_in))),
+             jax.random.normal(ks[5], (B, T, N)),
+             jax.random.normal(ks[6], (B, T, N)),
+             -jnp.exp(jax.random.normal(ks[7], (d_in, N)) * 0.3),
+             jnp.ones((d_in,)), jnp.zeros((B, d_in, N)))
+    rkv = [jax.random.normal(ks[i], (B, T, H, D)) for i in range(3)]
+    wkv = (*rkv, jax.nn.sigmoid(jax.random.normal(ks[3], (B, T, H, D))),
+           jax.random.normal(ks[4], (H, D)), jnp.zeros((B, H, D, D)))
+    return {
+        "rmsnorm": (lambda x, s: ops.rmsnorm(x, s),
+                    lambda x, s: ref.rmsnorm_ref(x, s),
+                    (jax.random.normal(ks[0], (5, 64)),
+                     jax.random.normal(ks[1], (64,)))),
+        "flash_segments": (
+            lambda q, k, v: ops.flash_attention(q, k, v, segment_ids=seg),
+            lambda q, k, v: ref.attention_ref(q, k, v, segment_ids=seg),
+            (q, *kv)),
+        "mamba_scan": (lambda *a: ops.mamba_scan(*a)[0],
+                       lambda *a: ref.mamba_scan_ref(*a)[0], mamba),
+        "wkv6": (lambda *a: ops.wkv6(*a)[0],
+                 lambda *a: ref.wkv6_ref(*a)[0], wkv),
+    }
+
+
+@pytest.mark.parametrize("op", ["rmsnorm", "flash_segments", "mamba_scan",
+                                "wkv6"])
+def test_ops_gradient_through_kernel(monkeypatch, op):
+    """The PPO update differentiates through ``ops``; ``pallas_call`` has
+    no transpose rule, so kernel ops take the reference's VJP — the
+    gradient must equal the reference's own, every differentiable input."""
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    fn, want_fn, args = _grad_cases()[op]
+    argnums = tuple(range(len(args)))
+    got = jax.grad(lambda *a: (fn(*a) ** 2).sum(), argnums)(*args)
+    want = jax.grad(lambda *a: (want_fn(*a) ** 2).sum(), argnums)(*args)
+    for g, w in zip(got, want):
+        assert_allclose(np.asarray(g), np.asarray(w), rtol=5e-4, atol=5e-4)
+
+
+@pytest.mark.parametrize("switch", ["REPRO_PALLAS_INTERPRET",
+                                    "REPRO_FORCE_REF"])
+def test_cpu_switches_raise_on_tpu(monkeypatch, switch):
+    """On a TPU backend the interpreter / reference switches must fail
+    loudly, never quietly replace the compiled kernel; on the CPU they
+    keep their meaning."""
+    x, s = jnp.ones((8, 128)), jnp.ones((128,))
+    monkeypatch.setenv(switch, "1")
+    want = ref.rmsnorm_ref(x, s)
+    assert_allclose(np.asarray(ops.rmsnorm(x, s)), np.asarray(want),
+                    rtol=1e-6, atol=1e-6)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match=switch):
+        ops.rmsnorm(x, s)
