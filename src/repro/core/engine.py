@@ -64,7 +64,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig, TreeConfig
 from repro.core import faults
-from repro.core.guard import annotated_transfer
+from repro.core.guard import annotated_transfer, spanned
 from repro.kernels import ops as kops
 from repro.kv.cache import OutOfPages, PagedKVState, bucket_pow2
 from repro.kv.layout import fuse_mla, interleave_kv
@@ -579,6 +579,8 @@ class TreeEngine:
 
     # -- prefill ------------------------------------------------------------------
 
+    @spanned("engine.prefill",
+             lambda self, prompts, *a, **k: {"queries": len(prompts)})
     def prefill_queries(self, prompts: List[List[int]],
                         prefix_embeds: Optional[np.ndarray] = None,
                         enc_frames: Optional[np.ndarray] = None
@@ -673,6 +675,8 @@ class TreeEngine:
 
     # -- fork ----------------------------------------------------------------------
 
+    @spanned("engine.fork",
+             lambda self, parents, **k: {"forks": len(parents)})
     def fork_paths(self, parents: Sequence[EnginePath], *,
                    resample: bool = True) -> List[EnginePath]:
         """Batched branch of a whole round: for every parent (repeat a
@@ -838,6 +842,8 @@ class TreeEngine:
 
     # -- segment decode ----------------------------------------------------------
 
+    @spanned("engine.decode", lambda self, paths, seg_len=None: {
+        "rows": len(paths), "seg_len": seg_len or self.tree_cfg.segment_len})
     def decode_segments(self, paths: List[EnginePath],
                         seg_len: Optional[int] = None
                         ) -> List[SegmentResult]:

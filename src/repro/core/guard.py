@@ -24,11 +24,27 @@ runtime half of the enforcement layer (the static half is
   offending call site; annotated ones are tallied per reason.
 
 * :func:`compile_count` / :func:`compile_delta` — a process-wide
-  compilation counter fed by ``jax.monitoring`` backend-compile events,
-  and :func:`compile_cache_size` for per-jitted-function trace-cache
-  sizes — together they turn "one compilation per bucket" into an
-  assertable number (``tests/test_guard.py``,
-  ``benchmarks/train_hotpath.py``'s ``recompiles`` field).
+  compilation counter, and :func:`compile_cache_size` for
+  per-jitted-function trace-cache sizes — together they turn "one
+  compilation per bucket" into an assertable number
+  (``tests/test_guard.py``, ``benchmarks/train_hotpath.py``'s
+  ``recompiles`` field).  Both read the per-program build counters
+  (:func:`build_counts`): one ``jax.monitoring`` listener keys every
+  jaxpr trace, lowering and backend compile (or persistent-cache load)
+  by the jitted function's name.  They are always on and cost nothing
+  until something compiles.
+
+* :func:`span` / :func:`tracing` / :func:`take_spans` — the program's
+  host spans.  Off (the default) ``span`` returns one shared no-op
+  context manager: nothing is timed or recorded.  On — ``tracing(True)``,
+  or while a ``jax.profiler.start_trace`` session runs — each span is
+  recorded in memory as ``(name, start_ns, end_ns, parent, attrs)`` and
+  opens a ``jax.profiler.TraceAnnotation("repro.<name>")`` so the
+  profiler's trace carries it too; the builds above become
+  ``build.<program>.<phase>`` spans.  Spans live in host code only:
+  nothing here enters a jitted body, wraps a jitted callable or sets a
+  ``jax.config`` option, so every program's HLO is the same with
+  tracing on or off.
 
 Known limits (documented, not silent): dunder conversions
 (``float(x)`` / ``int(x)`` on a device array) cannot be intercepted
@@ -41,9 +57,14 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
+import re
 import threading
+import time
 import traceback
-from typing import Any, Dict, List, Optional, Tuple
+import warnings
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
+                    Tuple)
 
 import jax
 import numpy as np
@@ -51,6 +72,7 @@ import numpy as np
 # the function the removed ``jax.core.trace_state_clean`` alias named (it
 # returns ``trace_ctx.is_top_level()``), so its meaning here is unchanged.
 from jax._src.core import trace_state_clean
+from jax._src import profiler as _jax_profiler
 
 __all__ = [
     "HotPathViolation",
@@ -60,6 +82,13 @@ __all__ = [
     "compile_count",
     "compile_delta",
     "compile_cache_size",
+    "ProgramBuilds",
+    "build_counts",
+    "Span",
+    "span",
+    "spanned",
+    "tracing",
+    "take_spans",
 ]
 
 
@@ -69,35 +98,298 @@ class HotPathViolation(RuntimeError):
 
 
 # ---------------------------------------------------------------------------
-# compile counter (jax.monitoring backend-compile events)
+# host spans
 # ---------------------------------------------------------------------------
 
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-_compile_lock = threading.Lock()
-_compile_state = {"count": 0, "registered": False}
+class Span(NamedTuple):
+    """One recorded span; ``parent`` indexes the same list (-1: none)."""
+
+    name: str
+    start_ns: int
+    end_ns: int
+    parent: int
+    attrs: Dict[str, Any]
 
 
-def _on_event_duration(event: str, duration: float, **_kw) -> None:
-    if event == _COMPILE_EVENT:
-        with _compile_lock:
-            _compile_state["count"] += 1
+_tracing_on = False
 
 
-def _ensure_listener() -> None:
-    if not _compile_state["registered"]:
-        with _compile_lock:
-            if not _compile_state["registered"]:
-                jax.monitoring.register_event_duration_secs_listener(
-                    _on_event_duration)
-                _compile_state["registered"] = True
+def _profiling() -> bool:
+    """Whether a ``jax.profiler.start_trace`` session is running.  JAX 0.9
+    has no public query; its session lives here.  (``TraceAnnotation.
+    is_enabled`` would also answer for host tracing that no session of
+    this process started.)"""
+    return _jax_profiler._profile_state.profile_session is not None
+
+
+# whether the last look saw a profiler session (see _recording)
+_in_session = False
+# a store that no reader empties stops growing here (about 100 MB)
+MAX_SPANS = 250_000
+_span_lock = threading.Lock()
+_spans: List[Optional[Span]] = []
+_warned_full = False
+_span_tls = threading.local()
+
+
+def tracing(on: bool) -> bool:
+    """Switch span recording on or off; returns the previous setting.
+    Off by default; a ``jax.profiler.start_trace`` session records
+    spans whatever the switch says, so a profiled window always carries
+    them."""
+    global _tracing_on
+    prev, _tracing_on = _tracing_on, bool(on)
+    return prev
+
+
+def _recording() -> bool:
+    """Whether a span would be kept now.  With the switch off, a profiler
+    session that began since the last look starts the store afresh, so
+    one session's spans never carry over into the next; a full store
+    keeps nothing more."""
+    global _in_session, _warned_full
+    if not _tracing_on:
+        if not _profiling():
+            if _in_session:
+                _in_session = False
+            return False
+        if not _in_session:
+            _in_session = True
+            take_spans()
+    if len(_spans) < MAX_SPANS:
+        return True
+    if not _warned_full:
+        _warned_full = True
+        warnings.warn(f"repro.core.guard: {MAX_SPANS} spans kept and none "
+                      "taken; later spans are dropped", RuntimeWarning,
+                      stacklevel=3)
+    return False
+
+
+def take_spans() -> List[Span]:
+    """The spans completed since the last call, in the order they were
+    opened (a build phase: when it ended); the store is cleared.  A span
+    still open when this runs is dropped from both lists."""
+    global _spans, _warned_full
+    with _span_lock:
+        out, _spans = _spans, []
+        _warned_full = False
+    index = {}
+    kept: List[Span] = []
+    for i, sp in enumerate(out):
+        if sp is not None:
+            index[i] = len(kept)
+            kept.append(sp)
+    return [sp._replace(parent=index.get(sp.parent, -1)) for sp in kept]
+
+
+def _stack() -> list:
+    st = getattr(_span_tls, "stack", None)
+    if st is None:
+        st = _span_tls.stack = []
+    return st
+
+
+def _open_parent(store: list) -> int:
+    stack = _stack()
+    return stack[-1][1] if stack and stack[-1][0] is store else -1
+
+
+class _NoSpan:
+    """The one span handed out while tracing is off."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+    def set(self, **attrs) -> None:
+        pass
+
+    def __bool__(self) -> bool:
+        return False
+
+
+_NO_SPAN = _NoSpan()
+
+
+class _LiveSpan:
+    __slots__ = ("name", "attrs", "_store", "_index", "_parent", "_start",
+                 "_ann")
+
+    def __init__(self, name: str, attrs: Dict[str, Any]):
+        self.name = name
+        self.attrs = attrs
+
+    def __enter__(self):
+        with _span_lock:
+            store = self._store = _spans
+            self._index = len(store)
+            store.append(None)
+        self._parent = _open_parent(store)
+        _stack().append((store, self._index))
+        self._ann = jax.profiler.TraceAnnotation("repro." + self.name)
+        self._ann.__enter__()
+        self._start = time.time_ns()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        end = time.time_ns()
+        self._ann.__exit__(*exc)
+        _stack().pop()
+        self._store[self._index] = Span(self.name, self._start, end,
+                                        self._parent, self.attrs)
+        return False
+
+    def set(self, **attrs) -> None:
+        """Add attributes known only once the span's work has run."""
+        self.attrs.update(attrs)
+
+    def __bool__(self) -> bool:
+        return True
+
+
+def span(name: str, **attrs):
+    """``with span("engine.decode", rows=R): ...`` — a host span around a
+    layer's work.  Off: the shared no-op (falsy, so ``if sp: sp.set(...)``
+    computes late attributes only when recording).  Wall clock in
+    nanoseconds (``time.time_ns``), the clock the profiler's host events
+    use.  Never put one inside a per-row or per-token loop."""
+    if not _recording():
+        return _NO_SPAN
+    return _LiveSpan(name, attrs)
+
+
+def spanned(name: str, attrs: Optional[Callable[..., Dict[str, Any]]] = None):
+    """Decorator for a host function or method: each call runs inside
+    ``span(name, **attrs(*args, **kwargs))``, ``attrs`` called only while
+    recording.  Never on a jitted callable: a program built inside the
+    call is built as without it."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def call(*args, **kwargs):
+            sp = (_LiveSpan(name, attrs(*args, **kwargs) if attrs else {})
+                  if _recording() else _NO_SPAN)
+            # one call line on or off: programs built inside take their
+            # source locations from this stack
+            with sp:
+                return fn(*args, **kwargs)
+        return call
+    return wrap
+
+
+def _record(name: str, start_ns: int, end_ns: int,
+            attrs: Dict[str, Any]) -> None:
+    """A span whose work has already run (a build phase, reported by
+    JAX at its end), under the span open on this thread."""
+    with _span_lock:
+        store = _spans
+        store.append(Span(name, start_ns, end_ns, _open_parent(store),
+                          attrs))
+
+
+# ---------------------------------------------------------------------------
+# per-program build counters (jax.monitoring compile events)
+# ---------------------------------------------------------------------------
+
+_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": 0,
+                 "/jax/compilation_cache/cache_misses": 1}
+# lowering and compile events name the module (``jit(decode_fn)``), the
+# trace event the function (``decode_fn``): both key as ``decode_fn``
+_MODULE_NAME = re.compile(r"^(?:jit|pmap)\((.*)\)$")
+
+
+@dataclasses.dataclass
+class ProgramBuilds:
+    """What building one program (a jitted function, by ``__name__``)
+    cost: traces to a jaxpr, lowerings to MLIR, and backend compiles or
+    persistent-cache loads, with the cache's hits and misses and the
+    seconds of each phase."""
+
+    traces: int = 0
+    lowerings: int = 0
+    compiles: int = 0
+    cache_hits: int = 0
+    cache_misses: int = 0
+    trace_s: float = 0.0
+    lower_s: float = 0.0
+    compile_s: float = 0.0
+
+
+_build_lock = threading.Lock()
+_builds: Dict[str, ProgramBuilds] = {}
+_build_state = {"compiles": 0, "pending": [0, 0]}
+
+
+def _program_name(fun_name: str) -> str:
+    m = _MODULE_NAME.match(fun_name)
+    return m.group(1) if m else fun_name
+
+
+def _on_build_phase(event: str, start: float, end: float, **kw) -> None:
+    phase = _PHASES.get(event)
+    if phase is None:
+        return
+    name = _program_name(str(kw.get("fun_name", "?")))
+    attrs: Dict[str, Any] = {}
+    with _build_lock:
+        b = _builds.get(name)
+        if b is None:
+            b = _builds[name] = ProgramBuilds()
+        if phase == "trace":
+            b.traces += 1
+            b.trace_s += end - start
+        elif phase == "lower":
+            b.lowerings += 1
+            b.lower_s += end - start
+        else:
+            # the cache's hit or miss fired inside this compile's span
+            pending = _build_state["pending"]
+            b.compiles += 1
+            b.compile_s += end - start
+            b.cache_hits += pending[0]
+            b.cache_misses += pending[1]
+            attrs = {"cache_hits": pending[0], "cache_misses": pending[1]}
+            _build_state["pending"] = [0, 0]
+            _build_state["compiles"] += 1
+    if _recording():
+        _record(f"build.{name}.{phase}", int(start * 1e9), int(end * 1e9),
+                attrs)
+
+
+def _on_cache_event(event: str, **_kw) -> None:
+    k = _CACHE_EVENTS.get(event)
+    if k is not None:
+        with _build_lock:
+            _build_state["pending"][k] += 1
+
+
+# one listener per process on the compile phases (their time spans carry
+# the durations too) and one on the persistent cache's hits and misses
+jax.monitoring.register_event_time_span_listener(_on_build_phase)
+jax.monitoring.register_event_listener(_on_cache_event)
+
+
+def build_counts() -> Dict[str, ProgramBuilds]:
+    """Per-program build counters since the process started (a copy)."""
+    with _build_lock:
+        return {k: dataclasses.replace(v) for k, v in _builds.items()}
 
 
 def compile_count() -> int:
-    """Total XLA backend compilations observed since the listener was
-    first armed (any call to this module arms it).  Use deltas — the
-    absolute value depends on what compiled before arming."""
-    _ensure_listener()
-    return _compile_state["count"]
+    """Total XLA backend compilations (persistent-cache loads included)
+    since this module was imported: the sum of every program's
+    ``compiles``.  Use deltas — the absolute value depends on what
+    compiled before."""
+    return _build_state["compiles"]
 
 
 @contextlib.contextmanager
@@ -326,8 +618,11 @@ def annotated_transfer(tree: Any, *, to: str = "host",
                          "(expected 'host' or 'device')")
     st = _state()
     st["annotating"] += 1
+    # a pull to the host is where the host waits for the device
+    sp = (_LiveSpan("transfer." + reason, {"to": to}) if _recording()
+          else _NO_SPAN)
     try:
-        with jax.transfer_guard("allow"):
+        with sp, jax.transfer_guard("allow"):
             if to == "host":
                 out = jax.device_get(tree)
             else:
@@ -365,7 +660,6 @@ def hot_path_guard(*, use_transfer_guard: Optional[bool] = None,
     itself).  Guards nest; the innermost report records the block's
     transfers and each active guard sees its own compile delta.
     """
-    _ensure_listener()
     if use_transfer_guard is None:
         use_transfer_guard = jax.default_backend() != "cpu"
     st = _state()

@@ -33,6 +33,7 @@ from repro.core import branching as br
 from repro.core.early_stop import segment_stop_reason, truncate_at_eos
 from repro.core.engine import TreeEngine
 from repro.core.fallback import pick_fallback
+from repro.core.guard import span
 from repro.core.tree import Path, QueryTree, Status, new_node_id
 from repro.kv.cache import OutOfPages
 
@@ -395,22 +396,24 @@ def sample_trees(engine: TreeEngine, prompts: List[List[int]],
     qslot_of: Dict[int, int] = {}
     try:
         # 1-2. prefill + init divergence ----------------------------------
-        roots = engine.prefill_queries(prompts,
-                                       prefix_embeds=prefix_embeds,
-                                       enc_frames=enc_frames)
-        for tree, root_ep in zip(trees, roots):
-            qslot_of[tree.query_idx] = root_ep.qslot
-            n_init = min(br.init_divergence(tree_cfg, rng),
-                         tree_cfg.max_width)
-            tree.init_div = n_init
-            eps = [root_ep] + engine.fork_paths([root_ep] * (n_init - 1))
-            tree.active = _quarantine_nonfinite(
-                tree,
-                [Path(query_idx=tree.query_idx, depth=0,
-                      node_ids=[tree.root_id], tokens=[], logprobs=[],
-                      ep=ep)
-                 for ep in eps],
-                engine, score_fn, report)
+        with span("sampler.prefill", queries=len(prompts)):
+            roots = engine.prefill_queries(prompts,
+                                           prefix_embeds=prefix_embeds,
+                                           enc_frames=enc_frames)
+            for tree, root_ep in zip(trees, roots):
+                qslot_of[tree.query_idx] = root_ep.qslot
+                n_init = min(br.init_divergence(tree_cfg, rng),
+                             tree_cfg.max_width)
+                tree.init_div = n_init
+                eps = [root_ep] + engine.fork_paths(
+                    [root_ep] * (n_init - 1))
+                tree.active = _quarantine_nonfinite(
+                    tree,
+                    [Path(query_idx=tree.query_idx, depth=0,
+                          node_ids=[tree.root_id], tokens=[], logprobs=[],
+                          ep=ep)
+                     for ep in eps],
+                    engine, score_fn, report)
 
         # 3. segment-synchronous search loop ------------------------------
         while True:
@@ -423,25 +426,30 @@ def sample_trees(engine: TreeEngine, prompts: List[List[int]],
                            for tree in trees if tree.preempted):
                     break
                 continue
-            for tree in trees:
-                tree.active = []
-            batch = _admit_for_decode(trees, batch, engine, tree_cfg,
-                                      report, score_fn)
-            results = engine.decode_segments([p.ep for _, p in batch])
-            report.decode_rounds += 1
-            for (tree, path), res in zip(batch, results):
-                _process_segment(tree, path, res.tokens, res.logprobs,
-                                 res.seg_logprob, tree_cfg, engine,
-                                 score_fn, finite=res.finite,
-                                 report=report)
-            for tree in trees:
-                _branch_tree(tree, tree_cfg, engine, rng, progress,
-                             score_fn, report)
-                _fallback_tree(tree, tree_cfg, engine, rng, guard,
-                               engine.n_prefix, report)
-                if tree.preempted:
-                    _regenerate_tree(tree, engine, tree_cfg, guard,
-                                     report, score_fn)
+            with span("sampler.round") as sp:
+                for tree in trees:
+                    tree.active = []
+                batch = _admit_for_decode(trees, batch, engine, tree_cfg,
+                                          report, score_fn)
+                forks0 = engine.stats.forks
+                results = engine.decode_segments([p.ep for _, p in batch])
+                report.decode_rounds += 1
+                for (tree, path), res in zip(batch, results):
+                    _process_segment(tree, path, res.tokens, res.logprobs,
+                                     res.seg_logprob, tree_cfg, engine,
+                                     score_fn, finite=res.finite,
+                                     report=report)
+                for tree in trees:
+                    _branch_tree(tree, tree_cfg, engine, rng, progress,
+                                 score_fn, report)
+                    _fallback_tree(tree, tree_cfg, engine, rng, guard,
+                                   engine.n_prefix, report)
+                    if tree.preempted:
+                        _regenerate_tree(tree, engine, tree_cfg, guard,
+                                         report, score_fn)
+                if sp:
+                    sp.set(rows=len(batch),
+                           forks=engine.stats.forks - forks0)
 
         # preempted paths the budget never recovered for: graceful
         # degradation means they are dropped as failed trajectories, not

@@ -38,7 +38,7 @@ import numpy as np
 from repro.core import faults
 from repro.core.early_stop import truncate_at_eos
 from repro.core.engine import EnginePath, TreeEngine
-from repro.core.guard import annotated_transfer
+from repro.core.guard import annotated_transfer, span
 from repro.data.tokenizer import ByteTokenizer
 from repro.kv.cache import OutOfPages, bucket_pow2
 from repro.kv.radix import RadixCache
@@ -89,6 +89,8 @@ class ServeReport:
     model_tokens: int = 0             # R*l per round over real rows
     evicted_pages: int = 0            # radix pages dropped under pressure
     max_admission_wait: int = 0       # rounds from visible to admitted
+    rows_dispatched: int = 0          # compiled rows (Rb) over dispatches
+    rows_real: int = 0                # running rows over dispatches
     virtual_time: float = 0.0
 
     @property
@@ -96,10 +98,6 @@ class ServeReport:
         """Fraction of admitted prompt tokens whose KV came from the
         cross-request radix cache instead of being recomputed."""
         return self.radix_hit_tokens / max(self.prompt_tokens, 1)
-
-    @property
-    def gen_token_ps(self) -> float:
-        return self.gen_tokens / max(self.virtual_time, 1e-9)
 
     @property
     def traj_ps(self) -> float:
@@ -307,6 +305,8 @@ class Scheduler:
                 forced_on[i, :nf] = True
             row_keys[i] = (np.uint32(self.base_seed), np.uint32(r.rid))
         tables[R:, 0] = eng.garbage_page
+        self.report.rows_dispatched += Rb
+        self.report.rows_real += R
 
         fn = eng.runner.get_serve_fn(Rb, l)
         (tok0, lp0, pos0, tables, slots, forced_tok, forced_on,
@@ -363,8 +363,9 @@ class Scheduler:
                 self._finish_request(r, "length")
 
     def _finish_request(self, r: Request, reason: str) -> None:
-        self.engine.release_path(r.ep)
-        self.running.remove(r)
+        with span("scheduler.finish", rid=r.rid):
+            self.engine.release_path(r.ep)
+            self.running.remove(r)
         r.state = "finished"
         r.finish_reason = reason
         self.report.finished += 1
@@ -374,9 +375,26 @@ class Scheduler:
     def step(self) -> None:
         """One scheduling round: admit, make room, dispatch one mixed
         prefill/decode serve segment."""
-        self._admit()
-        self._make_room()
-        self._dispatch()
+        rep = self.report
+        before = (rep.admitted, rep.preemptions, rep.rows_real,
+                  rep.rows_dispatched)
+        with span("scheduler.round", round=self.round) as sp:
+            with span("scheduler.admit") as asp:
+                n0 = len(self.running)
+                self._admit()
+                if asp:
+                    asp.set(rids=[r.rid for r in self.running[n0:]])
+            with span("scheduler.make_room"):
+                self._make_room()
+            with span("scheduler.dispatch") as dsp:
+                self._dispatch()
+                if dsp:
+                    dsp.set(rows=rep.rows_real - before[2],
+                            bucket=rep.rows_dispatched - before[3])
+            if sp:
+                sp.set(running=rep.rows_real - before[2],
+                       admitted=rep.admitted - before[0],
+                       preempted=rep.preemptions - before[1])
         self.round += 1
 
     def run(self, requests: Sequence[Request], *,

@@ -59,7 +59,7 @@ from repro.configs.base import ModelConfig, TrainConfig, TreeConfig
 from repro.core import advantage as adv_mod
 from repro.core import faults
 from repro.core.engine import TreeEngine
-from repro.core.guard import annotated_transfer
+from repro.core.guard import annotated_transfer, span
 from repro.core.loss import token_logprobs_from_logits
 from repro.core.sampler import sample_sequential, sample_trees
 from repro.core.tree import (
@@ -303,7 +303,8 @@ class RLTrainer:
     def rollout(self, num_queries: int, progress: float = 0.0
                 ) -> Tuple[List[QueryTree], TreeEngine]:
         samples, prompts = self._sample_queries(num_queries)
-        engine = self._make_engine()
+        with span("rl.engine_build"):
+            engine = self._make_engine()
         targets = [s.answer for s in samples]
         if self.mode == TrainerMode.GRPO:
             trees, _ = sample_sequential(engine, prompts, targets,
@@ -714,6 +715,11 @@ class RLTrainer:
         paper oversamples 3× and resamples up to 2 extra rounds if dynamic
         sampling starves the batch.
         """
+        with span("rl.step", step=self.step):
+            return self._train_step(num_queries, progress)
+
+    def _train_step(self, num_queries: Optional[int],
+                    progress: float) -> Dict[str, float]:
         t0 = time.time()
         nq = num_queries or self.train_cfg.batch_size
         all_trees: List[QueryTree] = []
@@ -732,12 +738,13 @@ class RLTrainer:
             rounds += 1
             if not self.train_cfg.dynamic_sampling:
                 break
-        if self.train_cfg.pack_sequences:
-            batch = self.build_batch_packed(all_trees)
-            metrics = self.update_packed(batch)
-        else:
-            batch = self.build_batch(all_trees)
-            metrics = self.update(batch)
+        packed = self.train_cfg.pack_sequences
+        with span("rl.pack"):
+            batch = (self.build_batch_packed(all_trees) if packed
+                     else self.build_batch(all_trees))
+        with span("rl.update"):
+            metrics = (self.update_packed(batch) if packed
+                       else self.update(batch))
         self.step += 1
         rewards = batch.rewards
         metrics.update(
