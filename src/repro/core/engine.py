@@ -33,7 +33,9 @@ The per-segment inner loop is device-resident end to end:
   slot copies go through ``PagedKVState.apply_forks`` as one jitted
   multi-layer dispatch.
 
-Device functions are cached per static shape bucket:
+Device functions are kept per static shape bucket in a
+:class:`~repro.kv.cache.ProgramTable`, which an owner (the RL trainer) may
+share across the engines it builds:
   prefill  (Q, Sp)      — flash-attention forward, paged KV write-out,
                           returns last-position logits (kept on device).
   decode   (R, l)       — lax.scan over l tokens; paged attention per attn
@@ -45,7 +47,7 @@ Device functions are cached per static shape bucket:
                           a request's stream is bitwise independent of
                           how arrivals were batched around it.
 
-The device half (params, KV pools, jitted-fn caches) lives on
+The device half (params, KV pools, the program table) lives on
 :class:`ModelRunner`; :class:`TreeEngine` layers path scheduling policy
 (allocation, forks, preemption, pressure) on top — the SGL-JAX-style
 Scheduler / ModelRunner split.  ``repro.core.scheduler`` drives the
@@ -66,7 +68,8 @@ from repro.configs.base import ModelConfig, TreeConfig
 from repro.core import faults
 from repro.core.guard import annotated_transfer, spanned
 from repro.kernels import ops as kops
-from repro.kv.cache import OutOfPages, PagedKVState, bucket_pow2
+from repro.kv.cache import OutOfPages, PagedKVState, ProgramTable, \
+    bucket_pow2
 from repro.kv.layout import fuse_mla, interleave_kv
 from repro.models import attention as attn
 from repro.models import moe as moe_mod
@@ -259,13 +262,14 @@ class TreeEngine:
                  max_slots: int = 256, max_queries: int = 64,
                  max_prompt_len: int = 512, enc_len: int = 64,
                  dtype=jnp.float32, seed: int = 0, fused_kv: bool = True,
-                 paged_num_buffers: int = 2):
+                 paged_num_buffers: int = 2,
+                 programs: Optional[ProgramTable] = None):
         self.runner = ModelRunner(
             params, cfg, tree_cfg, num_pages=num_pages,
             page_size=page_size, max_slots=max_slots,
             max_queries=max_queries, max_prompt_len=max_prompt_len,
             enc_len=enc_len, dtype=dtype, seed=seed, fused_kv=fused_kv,
-            paged_num_buffers=paged_num_buffers)
+            paged_num_buffers=paged_num_buffers, programs=programs)
         self.stats = EngineStats()
         # pressure callback: called with the page deficit when an alloc
         # fails; frees pages (retracting retained/active KV) and the
@@ -355,15 +359,13 @@ class TreeEngine:
     def n_prefix(self) -> int:
         return self.runner.n_prefix
 
-    @property
-    def _decode_fns(self):
-        return self.runner._decode_fns
-
-    @property
-    def _prefill_fns(self):
-        return self.runner._prefill_fns
-
     # -- misc -----------------------------------------------------------------
+
+    def close(self) -> None:
+        """Free the device state once no path will be decoded again
+        (see :meth:`ModelRunner.close`); ``stats`` and the allocators
+        stay readable."""
+        self.runner.close()
 
     def _next_key(self):
         return self.runner.next_key()
@@ -925,8 +927,10 @@ class TreeEngine:
 class ModelRunner:
     """Device-execution half of the Scheduler / ModelRunner split.
 
-    Owns the params, the paged KV state, the cross-attention pools and the
-    per-shape caches of jitted prefill / decode / serve functions.  It
+    Owns the params, the paged KV state and the cross-attention pools,
+    and takes its jitted prefill / decode / serve functions from
+    ``programs`` (a private :class:`ProgramTable` unless one is given)
+    under ``signature``, every static value those functions read.  It
     knows nothing about paths, forks or preemption — ``TreeEngine`` (tree
     rollouts) and ``repro.core.scheduler.Scheduler`` (continuous batching)
     are its two scheduling frontends.
@@ -937,7 +941,8 @@ class ModelRunner:
                  max_slots: int = 256, max_queries: int = 64,
                  max_prompt_len: int = 512, enc_len: int = 64,
                  dtype=jnp.float32, seed: int = 0, fused_kv: bool = True,
-                 paged_num_buffers: int = 2):
+                 paged_num_buffers: int = 2,
+                 programs: Optional[ProgramTable] = None):
         self.params = params
         self.cfg = cfg
         self.tree_cfg = tree_cfg
@@ -950,8 +955,10 @@ class ModelRunner:
         self.paged_num_buffers = paged_num_buffers
         max_len = max_prompt_len + tree_cfg.max_response_len + enc_len
         self.MP = -(-max_len // self.page_size) + 1
+        self.programs = programs if programs is not None else ProgramTable()
         self.kv = PagedKVState(cfg, num_pages, self.page_size, max_slots,
-                               dtype, fused_kv=fused_kv)
+                               dtype, fused_kv=fused_kv,
+                               programs=self.programs)
         # page 0 = garbage sink for padded-position writes; slot 0 = scratch
         self.garbage_page = self.kv.pool.alloc()
         assert self.garbage_page == 0
@@ -973,36 +980,46 @@ class ModelRunner:
         self.n_prefix = (cfg.frontend.num_prefix_tokens
                          if cfg.frontend is not None
                          and cfg.frontend.kind == "vision" else 0)
-        self._decode_fns: Dict[Tuple[int, int], Any] = {}
-        self._prefill_fns: Dict[Tuple[int, int, bool, bool], Any] = {}
-        self._serve_fns: Dict[Tuple[int, int], Any] = {}
+        self.windows = tuple(
+            cfg.sliding_window if (cfg.sliding_window > 0
+                                   and not cfg.is_global_attn_layer(i))
+            else 0 for i in range(cfg.num_layers))
+        # every static value the jitted functions read; runners with equal
+        # signatures share a table's programs bucket for bucket
+        self.signature = (cfg, tree_cfg.temperature, tree_cfg.top_p,
+                          self.page_size, jnp.dtype(dtype), fused_kv,
+                          paged_num_buffers, self.n_prefix, self.has_cross,
+                          enc_len, self.windows, num_pages, max_slots,
+                          self.MP, max_queries)
         self._key = jax.random.PRNGKey(seed)
 
     def next_key(self):
         self._key, sub = jax.random.split(self._key)
         return sub
 
-    def _window(self, layer_idx: int) -> int:
-        if (self.cfg.sliding_window > 0
-                and not self.cfg.is_global_attn_layer(layer_idx)):
-            return self.cfg.sliding_window
-        return 0
+    def close(self) -> None:
+        """Drop the KV pools, recurrent state and cross-attention pools.
+        Without it they live as long as the runner, and a runner held in
+        a reference cycle (a wrapped method, a callback) lives until the
+        cyclic collector runs, which host code that builds no programs
+        rarely triggers."""
+        self.kv.kv_pools = {}
+        self.kv.rec_state = {}
+        self.cross_pool = {}
 
     # =================== jitted device functions =================================
 
     def get_prefill_fn(self, Q: int, Sp: int, has_prefix: bool,
                        has_frames: bool):
-        key = (Q, Sp, has_prefix, has_frames)
-        if key not in self._prefill_fns:
-            self._prefill_fns[key] = self._build_prefill(Q, Sp)
-        return self._prefill_fns[key]
+        return self.programs.get(self.signature, "prefill",
+                                 (Q, Sp, has_prefix, has_frames),
+                                 lambda: self._build_prefill(Q, Sp))
 
     def _build_prefill(self, Q: int, Sp: int):
         cfg = self.cfg
         page = self.page_size
         n_pre = self.n_prefix
         pool_dtype = self.dtype
-        window_of = self._window
         fused = self.fused_kv
 
         def prefill_fn(params, pools, rec, cross, tokens, lengths, tables,
@@ -1128,16 +1145,12 @@ class ModelRunner:
         return jax.jit(prefill_fn)
 
     def get_decode_fn(self, R: int, l: int):
-        key = (R, l)
-        if key not in self._decode_fns:
-            self._decode_fns[key] = self._build_decode(R, l)
-        return self._decode_fns[key]
+        return self.programs.get(self.signature, "decode", (R, l),
+                                 lambda: self._build_decode(R, l))
 
     def get_serve_fn(self, R: int, l: int):
-        key = (R, l)
-        if key not in self._serve_fns:
-            self._serve_fns[key] = self._build_serve(R, l)
-        return self._serve_fns[key]
+        return self.programs.get(self.signature, "serve", (R, l),
+                                 lambda: self._build_serve(R, l))
 
     def _make_token_forward(self, R: int):
         """One decoding step for a (R,)-row batch, shared by the tree
@@ -1147,7 +1160,7 @@ class ModelRunner:
         cfg = self.cfg
         page = self.page_size
         pool_dtype = self.dtype
-        window_of = self._window
+        windows = self.windows
         has_cross = self.has_cross
         fused = self.fused_kv
         nbuf = self.paged_num_buffers
@@ -1235,7 +1248,7 @@ class ModelRunner:
                             new_pools[i] = pi
                             o = kops.fused_paged_attention(
                                 q, pi["kv"], tables, lengths,
-                                page_size=page, window=window_of(i),
+                                page_size=page, window=windows[i],
                                 num_buffers=nbuf)
                         else:
                             pi = {
@@ -1247,7 +1260,7 @@ class ModelRunner:
                             new_pools[i] = pi
                             o = kops.paged_attention(
                                 q, pi["k"], pi["v"], tables, lengths,
-                                page_size=page, window=window_of(i))
+                                page_size=page, window=windows[i])
                         y = o.reshape(R, -1) @ lp_["attn"]["w_o"]
                 elif kind == "mamba":
                     y1, st = ssm.mamba_forward(
@@ -1299,7 +1312,7 @@ class ModelRunner:
 
     def _build_decode(self, R: int, l: int):
         cfg = self.cfg
-        tc = self.tree_cfg
+        temperature, top_p = self.tree_cfg.temperature, self.tree_cfg.top_p
         has_cross = self.has_cross
         token_forward = self._make_token_forward(R)
 
@@ -1317,7 +1330,7 @@ class ModelRunner:
                 new_pools, new_rec_g, logits = token_forward(
                     params, pools, rec_g, cross_g, tok, pos, tables)
                 tnext, lpnext = sample_tokens(key_t, logits,
-                                              tc.temperature, tc.top_p)
+                                              temperature, top_p)
                 new_carry = (new_pools, new_rec_g, tnext, lpnext, pos + 1,
                              logits.astype(jnp.float32))
                 return new_carry, (tok, lp)
@@ -1357,7 +1370,7 @@ class ModelRunner:
             "serve loop needs token-complete contexts (no cross-KV / " \
             "modality prefix)"
         cfg = self.cfg
-        tc = self.tree_cfg
+        temperature, top_p = self.tree_cfg.temperature, self.tree_cfg.top_p
         token_forward = self._make_token_forward(R)
 
         def serve_fn(params, pools, rec, tok0, lp0, pos0, tables, slots,
@@ -1370,7 +1383,7 @@ class ModelRunner:
                 f_tok, f_on = xs
                 tok = jnp.where(f_on, f_tok, tok)
                 prev_lsm = jax.nn.log_softmax(
-                    prev_logits / max(tc.temperature, 1e-6), axis=-1)
+                    prev_logits / max(temperature, 1e-6), axis=-1)
                 lp = jnp.where(
                     f_on,
                     jnp.take_along_axis(prev_lsm, f_tok[:, None],
@@ -1380,7 +1393,7 @@ class ModelRunner:
                     params, pools, rec_g, None, tok, pos, tables)
                 keys = jax.vmap(jax.random.fold_in)(row_keys, pos + 1)
                 tnext, lpnext = sample_rows(keys, logits,
-                                            tc.temperature, tc.top_p)
+                                            temperature, top_p)
                 new_carry = (new_pools, new_rec_g, tnext, lpnext, pos + 1,
                              logits.astype(jnp.float32))
                 return new_carry, (tok, lp)

@@ -88,6 +88,10 @@ class _Tracker:
         self.pages: Dict[int, Dict[int, int]] = {}
         self.slot_free: Dict[int, Set[int]] = {}
         self.released_paths: Set[int] = set()
+        # every pool and allocator shadowed, held so that each id above
+        # names one object: a freed pool's id can come back as the next
+        # engine's pool, which would inherit a stale shadow
+        self._tracked: List[object] = []
 
     def violate(self, msg: str) -> None:
         self.report.violations.append(f"{msg} at {_call_site()}")
@@ -99,6 +103,7 @@ class _Tracker:
         if shadow is None:
             shadow = {p: int(c) for p, c in enumerate(pool.refcount) if c}
             self.pages[id(pool)] = shadow
+            self._tracked.append(pool)
         return shadow
 
     def _check_pool(self, pool: PagePool, shadow: Dict[int, int]) -> None:
@@ -170,6 +175,7 @@ class _Tracker:
         if shadow is None:
             shadow = set(alloc.free)
             self.slot_free[id(alloc)] = shadow
+            self._tracked.append(alloc)
         return shadow
 
     def slot_alloc(self, alloc: SlotAllocator, slot: int) -> None:

@@ -20,7 +20,7 @@ a running reduction, not a prefix (DESIGN.md §4).  Both kinds of fork copy
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -91,6 +91,41 @@ def bucket_pow2(n: int, minimum: int = 1) -> int:
     """Round up to the next power of two — THE jit-shape bucketing policy
     (engine batch/seq buckets and apply_forks pad buckets share it)."""
     return max(minimum, 1 << (max(n, 1) - 1).bit_length())
+
+
+class ProgramTable:
+    """Jitted engine programs, kept for as long as the table lives.
+
+    Keyed by ``(signature, kind, bucket)``: ``signature`` holds every
+    static value the program's closure reads, ``kind`` names the program
+    (``prefill``, ``decode``, ``serve``, ``fork``) and ``bucket`` its
+    shape bucket.  Owners with equal signatures get the same jitted
+    callable for a bucket, so a fresh engine over the same statics traces,
+    lowers and compiles nothing again; owners whose statics differ never
+    meet.  A kept program captures static values only, never an engine, a
+    pool or parameters, so the table pins no device state.
+    """
+
+    def __init__(self) -> None:
+        self._programs: Dict[tuple, Any] = {}
+        self.hits = 0       # lookups answered by a kept program
+        self.misses = 0     # lookups that built one
+
+    def get(self, signature: tuple, kind: str, bucket: tuple,
+            build: Callable[[], Any]) -> Any:
+        key = (signature, kind, bucket)
+        fn = self._programs.get(key)
+        if fn is None:
+            fn = self._programs[key] = build()
+            self.misses += 1
+        else:
+            self.hits += 1
+        return fn
+
+    def entries(self, signature: tuple, kind: str) -> Dict[tuple, Any]:
+        """``{bucket: program}`` of one signature's ``kind``."""
+        return {b: fn for (s, k, b), fn in self._programs.items()
+                if k == kind and s == signature}
 
 
 @dataclasses.dataclass
@@ -193,7 +228,8 @@ class PagedKVState:
     """
 
     def __init__(self, cfg: ModelConfig, num_pages: int, page_size: int,
-                 max_slots: int, dtype=jnp.float32, fused_kv: bool = True):
+                 max_slots: int, dtype=jnp.float32, fused_kv: bool = True,
+                 programs: Optional[ProgramTable] = None):
         self.cfg = cfg
         self.page_size = page_size
         self.pool = PagePool(num_pages)
@@ -254,8 +290,12 @@ class PagedKVState:
                 }
         # whisper cross-attention KV: per request, shared by every branch
         self.cross_kv: Optional[tuple] = None
-        # jitted fork-copy dispatches, cached per (page-, slot-count) bucket
-        self._fork_fns: Dict[tuple, object] = {}
+        # jitted fork-copy dispatches, one per (page-, slot-count) bucket;
+        # a fork program reads only the pools' shapes, which these
+        # statics fix, so pools of equal signature share it
+        self.programs = programs if programs is not None else ProgramTable()
+        self.signature = (cfg, num_pages, page_size, max_slots,
+                          jnp.dtype(dtype), fused_kv)
 
     # -- host bookkeeping ---------------------------------------------------
 
@@ -295,37 +335,8 @@ class PagedKVState:
         return (src_d, dst_d, nb)
 
     def _get_fork_fn(self, n_pages: int, n_slots: int):
-        """Jitted multi-layer copy, shaped by which state kinds fork this
-        round.  The function only takes (and returns) the pytrees it
-        mutates — an untouched pool routed through jit would come back as
-        a fresh output buffer, i.e. a full pool copy per round."""
-        key = (n_pages, n_slots)
-        if key not in self._fork_fns:
-            def copy_rows(tree, src, dst):
-                return {i: {k: v.at[dst].set(v[src]) for k, v in st.items()}
-                        for i, st in tree.items()}
-
-            if n_pages and n_slots:
-                def fork_fn(pools, rec, psrc, pdst, ssrc, sdst):
-                    return (copy_rows(pools, psrc, pdst),
-                            copy_rows(rec, ssrc, sdst))
-                donate = (0, 1)
-            elif n_pages:
-                def fork_fn(pools, psrc, pdst):
-                    return copy_rows(pools, psrc, pdst)
-                donate = (0,)
-            else:
-                def fork_fn(rec, ssrc, sdst):
-                    return copy_rows(rec, ssrc, sdst)
-                donate = (0,)
-            # donate the pools/rec buffers (the caller rebinds them to the
-            # result) so XLA scatters the few forked rows in place instead
-            # of copying whole (num_pages, ...) arrays each round; CPU has
-            # no donation support and would warn per dispatch.
-            if jax.default_backend() == "cpu":
-                donate = ()
-            self._fork_fns[key] = jax.jit(fork_fn, donate_argnums=donate)
-        return self._fork_fns[key]
+        return self.programs.get(self.signature, "fork", (n_pages, n_slots),
+                                 lambda: _build_fork_fn(n_pages, n_slots))
 
     def apply_forks(self, page_src: List[int], page_dst: List[int],
                     slot_src: List[int] = (), slot_dst: List[int] = ()
@@ -378,3 +389,34 @@ class PagedKVState:
                 per_tok = int(np.prod(arr.shape[2:])) * arr.dtype.itemsize
                 total += per_tok
         return total
+
+
+def _build_fork_fn(n_pages: int, n_slots: int):
+    """Jitted multi-layer copy, shaped by which state kinds fork this
+    round.  The function only takes (and returns) the pytrees it
+    mutates — an untouched pool routed through jit would come back as
+    a fresh output buffer, i.e. a full pool copy per round."""
+    def copy_rows(tree, src, dst):
+        return {i: {k: v.at[dst].set(v[src]) for k, v in st.items()}
+                for i, st in tree.items()}
+
+    if n_pages and n_slots:
+        def fork_fn(pools, rec, psrc, pdst, ssrc, sdst):
+            return (copy_rows(pools, psrc, pdst),
+                    copy_rows(rec, ssrc, sdst))
+        donate = (0, 1)
+    elif n_pages:
+        def fork_fn(pools, psrc, pdst):
+            return copy_rows(pools, psrc, pdst)
+        donate = (0,)
+    else:
+        def fork_fn(rec, ssrc, sdst):
+            return copy_rows(rec, ssrc, sdst)
+        donate = (0,)
+    # donate the pools/rec buffers (the caller rebinds them to the
+    # result) so XLA scatters the few forked rows in place instead
+    # of copying whole (num_pages, ...) arrays each round; CPU has
+    # no donation support and would warn per dispatch.
+    if jax.default_backend() == "cpu":
+        donate = ()
+    return jax.jit(fork_fn, donate_argnums=donate)

@@ -72,6 +72,7 @@ from repro.core.tree import (
 from repro.data.reward import reward_fn
 from repro.data.synthetic_math import MathTaskGenerator
 from repro.data.tokenizer import ByteTokenizer
+from repro.kv.cache import ProgramTable
 from repro.models.model import forward, init_params
 from repro.optim import (
     AdamWState,
@@ -224,6 +225,9 @@ class RLTrainer:
         self.gen = MathTaskGenerator(data_seed, min_difficulty,
                                      max_difficulty)
         self.engine_kwargs = dict(engine_kwargs or {})
+        # the jitted engine programs every engine of this trainer shares:
+        # each step's fresh engine finds the buckets earlier steps built
+        self.programs = ProgramTable()
         self._update_fns: Dict[Tuple[int, int], Any] = {}
         self._packed_update_fns: Dict[Tuple[int, int, int], Any] = {}
         self._legacy_update_fns: Dict[Tuple[int, int], Any] = {}
@@ -282,10 +286,11 @@ class RLTrainer:
     # -- engine ----------------------------------------------------------------
 
     def _make_engine(self) -> TreeEngine:
-        """Fresh engine view over the *current* params (on-policy rollout)."""
+        """Fresh engine view over the *current* params (on-policy rollout):
+        fresh pools, allocator and seed; the programs are the trainer's."""
         return TreeEngine(self.params, self.cfg, self.tree_cfg,
                           seed=int(self._rng.integers(2 ** 31)),
-                          **self.engine_kwargs)
+                          programs=self.programs, **self.engine_kwargs)
 
     # -- rollout ---------------------------------------------------------------
 
@@ -303,8 +308,11 @@ class RLTrainer:
     def rollout(self, num_queries: int, progress: float = 0.0
                 ) -> Tuple[List[QueryTree], TreeEngine]:
         samples, prompts = self._sample_queries(num_queries)
-        with span("rl.engine_build"):
+        with span("rl.engine_build") as sp:
             engine = self._make_engine()
+            if sp:
+                # the program table's lookups in the rollouts so far
+                sp.set(hits=self.programs.hits, misses=self.programs.misses)
         targets = [s.answer for s in samples]
         if self.mode == TrainerMode.GRPO:
             trees, _ = sample_sequential(engine, prompts, targets,
@@ -735,6 +743,9 @@ class RLTrainer:
             trees, engine = self.rollout(n, progress)
             all_trees.extend(trees)
             sample_tokens += engine.stats.model_tokens
+            # free the rollout's KV pools now, for the update, and not
+            # whenever the engine happens to be collected
+            engine.close()
             rounds += 1
             if not self.train_cfg.dynamic_sampling:
                 break
@@ -880,7 +891,8 @@ class RLTrainer:
             init_divergence_low=k, init_divergence_high=k,
             branch_factor=1, fallback=False)
         engine = TreeEngine(self.params, self.cfg, eval_tree_cfg,
-                            seed=seed, **self.engine_kwargs)
+                            seed=seed, programs=self.programs,
+                            **self.engine_kwargs)
         trees, _ = sample_trees(engine, prompts,
                                 [s.answer for s in samples],
                                 eval_tree_cfg, rng=__import__(

@@ -168,7 +168,8 @@ def test_warm_decode_round_zero_transfers_zero_compiles():
     assert rep.compiles == 0
     assert "decode-pack" in rep.annotated_reasons
     assert "decode-segment" in rep.annotated_reasons
-    for fn in eng._decode_fns.values():
+    for fn in eng.runner.programs.entries(eng.runner.signature,
+                                          "decode").values():
         assert compile_cache_size(fn) in (1, -1)
 
 
@@ -187,7 +188,7 @@ def test_warm_fork_round_zero_transfers():
         child = eng.fork_path(root)         # warm, same pad bucket
     assert rep.violations == []
     assert "fork-tables" in rep.annotated_reasons
-    for fn in eng.kv._fork_fns.values():
+    for fn in eng.kv.programs.entries(eng.kv.signature, "fork").values():
         assert compile_cache_size(fn) in (1, -1)
     eng.release_path(child)
 

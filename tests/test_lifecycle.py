@@ -121,6 +121,18 @@ def test_clean_slot_churn_is_silent():
     assert rep.slot_allocs == 16 and rep.slot_releases == 16
 
 
+def test_short_lived_pools_are_silent():
+    """Each engine's pool and slot allocator live one rollout; a freed
+    one's id must not hand its shadow to the next one's."""
+    with lifecycle_guard() as rep:
+        for _ in range(16):
+            pool, slots = PagePool(num_pages=4), SlotAllocator(num_slots=4)
+            pool.alloc()
+            slots.alloc()
+    assert rep.violations == []
+    assert rep.page_allocs == 16 and rep.slot_allocs == 16
+
+
 # ---------------------------------------------------------------------------
 # path FSM
 # ---------------------------------------------------------------------------

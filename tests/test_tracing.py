@@ -14,6 +14,7 @@ from repro.configs.base import TrainConfig, TreeConfig
 from repro.core import guard
 from repro.core.engine import TreeEngine
 from repro.core.scheduler import Request, Scheduler
+from repro.kv.cache import ProgramTable
 from repro.models.model import init_params
 from repro.rl.packing import PackedRolloutBatch
 from repro.rl.trainer import RLTrainer, TrainerMode
@@ -234,14 +235,16 @@ def test_same_lowerings_per_train_step_with_tracing_on_and_off():
     tr.train_step()                         # builds what every step shares
     with tracing(False):
         n0 = _lowerings()
+        tr.programs = ProgramTable()        # the step builds its programs
         tr.train_step()
         off = _lowerings() - n0
     with tracing(True):
         n0 = _lowerings()
+        tr.programs = ProgramTable()
         tr.train_step()
         on = _lowerings() - n0
         spans = guard.take_spans()
-    assert off == on > 0                    # a fresh engine every step
+    assert off == on > 0                    # each step's engine programs
     names = [s.name for s in spans]
     for name in ("rl.step", "rl.engine_build", "rl.pack", "rl.update",
                  "sampler.prefill", "sampler.round", "engine.decode",
